@@ -40,9 +40,12 @@ import numpy as np
 
 from repro.analysis.roofline import HBM_BW, roofline
 from repro.core.codecs import get_codec, packed_codecs
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.config import ModelConfig
-from repro.models.model import init_params
-from repro.serve import ServeEngine, prequantize_params, tree_nbytes
+from repro.models.model import init_caches, init_params
+from repro.serve import ServeEngine, init_packed_params, tree_nbytes
+
+SEED = 0            # weights; the traffic trace has its own seed
 
 
 def build_cfg(args, fmt: str) -> ModelConfig:
@@ -65,13 +68,15 @@ def decode_roofline(cfg, weight_bytes: int, kv_bytes: int, batch: int):
     return terms, tok_s, step_bytes / batch
 
 
-def bench_format(fmt: str, args, params, prompts) -> dict:
+def bench_format(fmt: str, args, prompts) -> dict:
     """Pack + serve one codec on the shared traffic trace; returns the
     per-format summary row."""
     cfg = build_cfg(args, fmt)
-    packed = prequantize_params(params, cfg)
+    key = jax.random.PRNGKey(SEED)
+    packed = init_packed_params(key, cfg)
 
-    dense_bytes = tree_nbytes(params)
+    dense_bytes = tree_nbytes(jax.eval_shape(lambda k: init_params(k, cfg),
+                                             key))
     packed_bytes = tree_nbytes(packed)
     from repro.models.quant import PackedWeight
     gemm_packed = gemm_dense = 0
@@ -128,9 +133,8 @@ def bench_format(fmt: str, args, params, prompts) -> dict:
     # -- modeled: HBM bytes/token + v5e roofline bound ----------------------
     kv_packed = eng.kv_bytes()
     bf16_cfg = dataclasses.replace(cfg, quant="none", kv_quant="none")
-    bf16_eng = ServeEngine(params, bf16_cfg, n_slots=args.slots,
-                           max_len=args.max_len)
-    kv_bf16 = bf16_eng.kv_bytes()
+    kv_bf16 = tree_nbytes(jax.eval_shape(lambda: init_caches(
+        bf16_cfg, args.slots, args.max_len, per_slot=True)))
 
     t_p, tok_p, bpt_p = decode_roofline(cfg, packed_bytes, kv_packed,
                                         args.slots)
@@ -155,7 +159,7 @@ def bench_format(fmt: str, args, params, prompts) -> dict:
     }
 
 
-def bench_chaos(args, params, prompts) -> int:
+def bench_chaos(args, prompts) -> int:
     """Fault-injection drill: run the trace under a seeded fault plan and
     report recovery. Returns a process exit code (0 = engine survived and
     completed work, 1 = containment failed)."""
@@ -165,7 +169,9 @@ def bench_chaos(args, params, prompts) -> int:
 
     fmt = args.fmt[0]
     cfg = build_cfg(args, fmt)
-    packed = prequantize_params(params, cfg)
+    key = jax.random.PRNGKey(SEED)
+    packed = init_packed_params(key, cfg)
+    params = init_params(key, cfg)      # dense source for exact repair
     guard = GuardConfig(retry_backoff_s=0.01, seed=args.chaos_seed)
     eng = ServeEngine(packed, cfg, n_slots=args.slots, max_len=args.max_len,
                       prefill_chunk=args.prefill_chunk,
@@ -240,6 +246,7 @@ def main():
     ap.add_argument("--chaos-watchdog-s", type=float, default=5.0,
                     help="per-launch watchdog budget during --chaos")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.obs_out:
         os.environ.setdefault("REPRO_OBS", "1")
@@ -251,12 +258,11 @@ def main():
     lens = rng.integers(args.prompt_len // 2, args.prompt_len + 1,
                         args.requests)
     prompts = [list(map(int, rng.integers(0, 4096, n))) for n in lens]
-    params = init_params(jax.random.PRNGKey(0), build_cfg(args, "m2xfp"))
 
     if args.chaos:
-        return bench_chaos(args, params, prompts)
+        return bench_chaos(args, prompts)
 
-    rows = [bench_format(fmt, args, params, prompts) for fmt in args.fmt]
+    rows = [bench_format(fmt, args, prompts) for fmt in args.fmt]
     if len(rows) > 1:
         print("per-format throughput (same traffic trace):")
         for r in rows:

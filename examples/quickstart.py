@@ -12,9 +12,11 @@ from repro.core import (
     quantize_nvfp4, quantize_smx4, quantize_weight_m2xfp, run_strategy,
 )
 from repro.kernels import m2xfp_matmul, m2xfp_quantize, pack_w_sgem
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     rng = np.random.default_rng(0)
     # LLM-like tensor: heavy-tailed with outlier channels
     x = jnp.asarray(rng.standard_t(4, (256, 1024)).astype(np.float32)

@@ -1,10 +1,10 @@
 """Serving driver — thin wrapper over the packed-weight engine.
 
 Pipeline (the paper's deployment path, repro.serve):
-  1. offline prequantization: bf16 params -> the packed streams of the
-     --fmt codec (m2xfp: Sg-EM, 4.5 bits/element resident; any packable
-     repro.core.codecs entry; weights never rematerialize in bf16),
-     round-tripped through a packed checkpoint;
+  1. seeded packed weights, built one layer at a time: the packed
+     streams of the --fmt codec (m2xfp: Sg-EM, 4.5 bits/element resident;
+     any packable repro.core.codecs entry; weights never rematerialize in
+     bf16), round-tripped through a packed checkpoint;
   2. continuous-batching decode: requests with different prompt lengths
      share the batch, admitted/evicted per slot while the engine keeps
      stepping (quantized KV-cache pages with --kv-quant).
@@ -19,10 +19,11 @@ import jax
 import numpy as np
 
 from repro.core.codecs import packed_codecs
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.config import ModelConfig
 from repro.models.model import init_params
 from repro.serve import (
-    ServeEngine, load_packed_checkpoint, prequantize_params,
+    ServeEngine, init_packed_params, load_packed_checkpoint,
     save_packed_checkpoint, tree_nbytes,
 )
 
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--layers", type=int, default=4)
     ap.add_argument("--kv-quant", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = ModelConfig(
         name="serve-lm", family="dense", n_layers=args.layers,
@@ -48,9 +50,10 @@ def main():
         quant_format=args.fmt,
         kv_quant="m2xfp" if args.kv_quant else "none")
 
-    params = init_params(jax.random.PRNGKey(0), cfg)
-    packed = prequantize_params(params, cfg)
-    print(f"weights: {tree_nbytes(params) / 2**20:.1f} MiB bf16 -> "
+    key = jax.random.PRNGKey(0)
+    dense = jax.eval_shape(lambda k: init_params(k, cfg), key)
+    packed = init_packed_params(key, cfg)
+    print(f"weights: {tree_nbytes(dense) / 2**20:.1f} MiB bf16 -> "
           f"{tree_nbytes(packed) / 2**20:.1f} MiB packed {args.fmt}")
 
     # the engine loads from the packed checkpoint, proving bf16 weights are

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.checkpoint import CheckpointManager
 from repro.data.pipeline import DataConfig, Prefetcher, SyntheticLM
+from repro.launch.compile_cache import use_compile_cache
 from repro.distributed.straggler import PreemptionGuard, StragglerMonitor
 from repro.models.config import ModelConfig
 from repro.models.model import loss_fn
@@ -34,6 +35,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="experiments/artifacts/train_lm")
     ap.add_argument("--microbatches", type=int, default=1)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = ModelConfig(
         name="train-lm", family="dense", n_layers=args.layers,

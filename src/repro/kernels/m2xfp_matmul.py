@@ -39,11 +39,14 @@ DEFAULT_BK = 512
 
 
 def _decode_codes(codes_u8: jax.Array, bk: int):
-    """u8 (bk/2, n) group-half-interleaved -> (mag f32 (bk, n), neg bool)."""
+    """u8 (bk/2, n) group-half-interleaved -> (mag f32 (bk, n), neg bool).
+
+    Every packed stream is widened to int32 before it is shifted or masked:
+    Mosaic cannot legalize shifts on 8-bit vectors."""
     n = codes_u8.shape[-1]
-    pg = codes_u8.reshape(bk // GROUP, 16, n)
-    lo = (pg & 0xF).astype(jnp.int32)
-    hi = (pg >> 4).astype(jnp.int32)
+    pg = codes_u8.astype(jnp.int32).reshape(bk // GROUP, 16, n)
+    lo = pg & 0xF
+    hi = pg >> 4
     c = jnp.concatenate([lo, hi], axis=1).reshape(bk, n)   # natural K order
     mag = fp4_mag_from_code(c & 7)
     return mag, (c & 8) != 0
@@ -58,9 +61,10 @@ def _expand_groups(v: jax.Array, bk: int):
 def _expand_subgroup_meta(meta_u8: jax.Array, bk: int):
     """u8 (bk/32, n) -> int32 (bk, n): 2-bit field of each row's subgroup."""
     n = meta_u8.shape[-1]
+    meta = meta_u8.astype(jnp.int32)
     fields = jnp.stack(
-        [(meta_u8 >> (2 * j)) & 0x3 for j in range(N_SUB)], axis=1
-    ).astype(jnp.int32)                                     # (bk/32, 4, n)
+        [(meta >> (2 * j)) & 0x3 for j in range(N_SUB)], axis=1
+    )                                                       # (bk/32, 4, n)
     full = jnp.broadcast_to(
         fields[:, :, None, :], (bk // GROUP, N_SUB, SUBGROUP, n))
     return full.reshape(bk, n)
@@ -90,9 +94,10 @@ def _decode_x_elem_em(xc_ref, xs_ref, xm_ref, bk: int) -> jax.Array:
     is_max = c4s == cmax
     first = jnp.cumsum(is_max.astype(jnp.int32), axis=2) == 1
     top1 = is_max & first                                    # lowest index tie
+    xm = xm_ref[...].astype(jnp.int32)
     meta = jnp.stack(
-        [(xm_ref[...] >> (2 * j)) & 0x3 for j in range(N_SUB)], axis=1
-    ).astype(jnp.int32)[:, :, None, :]                       # (bk/32,4,1,bm)
+        [(xm >> (2 * j)) & 0x3 for j in range(N_SUB)], axis=1
+    )[:, :, None, :]                                         # (bk/32,4,1,bm)
     c6 = jnp.maximum((cmax << 2) | meta, 1) - 1
     v6 = fp6_mag_from_code(c6)
     vals = jnp.where(top1, jnp.broadcast_to(v6, c4s.shape),

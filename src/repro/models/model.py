@@ -55,12 +55,8 @@ def _init_attn_block(key, cfg, dtype=jnp.bfloat16) -> dict:
     return p
 
 
-def _stack(keys, init_fn):
-    return jax.vmap(init_fn)(keys)
-
-
-def init_params(key, cfg, dtype=jnp.bfloat16) -> dict:
-    keys = jax.random.split(key, 8)
+def _init_unstacked(keys, cfg, dtype) -> dict:
+    """Every leaf of ``init_params`` that is not stacked over layers."""
     params: dict = {
         "embed": init_embedding(keys[0], cfg.vocab_size, cfg.d_model, dtype),
         "final_norm": init_rms_norm(cfg.d_model),
@@ -68,29 +64,42 @@ def init_params(key, cfg, dtype=jnp.bfloat16) -> dict:
     if not cfg.tie_embeddings:
         params["lm_head"] = init_embedding(
             keys[1], cfg.vocab_size, cfg.d_model, dtype).T
-
-    kinds = cfg.kinds
     if cfg.family == "ssm":                                  # xlstm pairs
         n_pairs = cfg.n_layers // 2
-        params["mlstm"] = _stack(
-            jax.random.split(keys[2], n_pairs),
-            lambda k: xl.init_mlstm(k, cfg, dtype))
         params["mlstm_norm"] = jnp.ones((n_pairs, cfg.d_model), jnp.float32)
-        params["slstm"] = _stack(
-            jax.random.split(keys[3], n_pairs),
-            lambda k: xl.init_slstm(k, cfg, dtype))
         params["slstm_norm"] = jnp.ones((n_pairs, cfg.d_model), jnp.float32)
     elif cfg.family == "hybrid":                             # zamba2
-        n_mamba = sum(1 for k in kinds if k == "mamba")
-        params["mamba"] = _stack(
-            jax.random.split(keys[2], n_mamba),
-            lambda k: mb.init_mamba2(k, cfg, dtype))
+        n_mamba = sum(1 for k in cfg.kinds if k == "mamba")
         params["mamba_norm"] = jnp.ones((n_mamba, cfg.d_model), jnp.float32)
         params["shared_attn"] = _init_attn_block(keys[3], cfg, dtype)
-    else:                                                    # attention LMs
-        params["layers"] = _stack(
-            jax.random.split(keys[2], cfg.n_layers),
-            lambda k: _init_attn_block(k, cfg, dtype))
+    return params
+
+
+def _layer_stacks(keys, cfg, dtype) -> dict:
+    """Layer-stacked groups of ``init_params``: name -> (per-layer keys,
+    one-layer init). ``init_params`` vmaps each init over its keys; the
+    per-layer packed builder (``repro.serve.prequant``) maps it instead."""
+    if cfg.family == "ssm":                                  # xlstm pairs
+        n_pairs = cfg.n_layers // 2
+        return {
+            "mlstm": (jax.random.split(keys[2], n_pairs),
+                      lambda k: xl.init_mlstm(k, cfg, dtype)),
+            "slstm": (jax.random.split(keys[3], n_pairs),
+                      lambda k: xl.init_slstm(k, cfg, dtype)),
+        }
+    if cfg.family == "hybrid":                               # zamba2
+        n_mamba = sum(1 for k in cfg.kinds if k == "mamba")
+        return {"mamba": (jax.random.split(keys[2], n_mamba),
+                          lambda k: mb.init_mamba2(k, cfg, dtype))}
+    return {"layers": (jax.random.split(keys[2], cfg.n_layers),   # attention
+                       lambda k: _init_attn_block(k, cfg, dtype))}
+
+
+def init_params(key, cfg, dtype=jnp.bfloat16) -> dict:
+    keys = jax.random.split(key, 8)
+    params = _init_unstacked(keys, cfg, dtype)
+    for name, (layer_keys, init) in _layer_stacks(keys, cfg, dtype).items():
+        params[name] = jax.vmap(init)(layer_keys)
     return params
 
 

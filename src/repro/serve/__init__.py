@@ -9,8 +9,8 @@ from .guard import (  # noqa: F401
     StreamIntegrityError, TransientStepError, verify_packed_tree,
 )
 from .prequant import (  # noqa: F401
-    load_packed_checkpoint, packed_template, prequantize_checkpoint,
-    prequantize_params, save_packed_checkpoint,
+    init_packed_params, load_packed_checkpoint, packed_template,
+    prequantize_checkpoint, prequantize_params, save_packed_checkpoint,
 )
 from .scheduler import (  # noqa: F401
     AdmissionError, Request, SlotScheduler,
@@ -20,7 +20,7 @@ __all__ = [
     "AdmissionError", "DEGRADED", "EngineFailedError", "EngineGuard",
     "FAILED", "GuardConfig", "HEALTHY", "Request", "ServeEngine",
     "ServeStats", "SlotScheduler", "StreamIntegrityError",
-    "TransientStepError", "load_packed_checkpoint", "packed_template",
-    "prequantize_checkpoint", "prequantize_params", "save_packed_checkpoint",
-    "tree_nbytes", "verify_packed_tree",
+    "TransientStepError", "init_packed_params", "load_packed_checkpoint",
+    "packed_template", "prequantize_checkpoint", "prequantize_params",
+    "save_packed_checkpoint", "tree_nbytes", "verify_packed_tree",
 ]

@@ -25,16 +25,21 @@ interchangeable), with an actionable message.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from repro.checkpoint import read_manifest, restore_state, save_state
-from repro.models.model import init_params, pack_params_for_serving
+from repro.models.model import (
+    _init_unstacked, _layer_stacks, init_params, pack_params_for_serving,
+)
 
 __all__ = [
-    "prequantize_params", "packed_template", "save_packed_checkpoint",
-    "load_packed_checkpoint", "prequantize_checkpoint",
+    "prequantize_params", "init_packed_params", "packed_template",
+    "save_packed_checkpoint", "load_packed_checkpoint",
+    "prequantize_checkpoint",
 ]
 
 # v1 predates the codec registry and implies codec="m2xfp"; v2 records the
@@ -57,6 +62,38 @@ def prequantize_params(params: dict, cfg) -> dict:
     weight becomes a codec-tagged ``PackedTensor``; embeddings / norms /
     recurrence params stay bf16)."""
     return pack_params_for_serving(params, _serve_cfg(cfg))
+
+
+def init_packed_params(key, cfg) -> dict:
+    """Seeded packed tree of ``prequantize_params(init_params(key, cfg),
+    cfg)``, built without ever holding the dense layer stacks: each stack
+    is initialized and packed one layer per step of a ``lax.map`` on the
+    default device, so peak memory is the packed tree plus one dense
+    layer. Embedding, ``lm_head`` and norms stay bf16/f32 as there.
+
+    Attention families match the two-step path bit for bit. The f32
+    recurrence leaves of xlstm/zamba2 stacks (sLSTM ``r``, Mamba2
+    ``A_log``) equal those of ``jax.jit(init_params)`` and may differ from
+    eager ``init_params`` in the last bit."""
+    scfg = _serve_cfg(cfg)
+    keys = jax.random.split(key, 8)
+    packed = jax.jit(lambda ks: pack_params_for_serving(
+        _init_unstacked(ks, scfg, jnp.bfloat16), scfg))(keys)
+    for name, (layer_keys, init) in _layer_stacks(
+            keys, scfg, jnp.bfloat16).items():
+        packed[name] = jax.jit(functools.partial(
+            _map_pack_layers, name, init, scfg))(layer_keys)
+    return packed
+
+
+def _map_pack_layers(name, init, cfg, layer_keys):
+    def one(k):
+        # a leading layer axis of 1 sends the layer through the very
+        # vmapped packing that pack_params_for_serving gives a stack
+        layer = jax.tree.map(lambda x: x[None], init(k))
+        packed = pack_params_for_serving({name: layer}, cfg)[name]
+        return jax.tree.map(lambda x: x[0], packed)
+    return jax.lax.map(one, layer_keys)
 
 
 def packed_template(cfg) -> dict:

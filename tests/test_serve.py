@@ -69,6 +69,69 @@ def test_packed_tree_is_4p5_bits_on_gemm_weights(packed_model):
     assert tree_nbytes(packed) < tree_nbytes(params)
 
 
+@pytest.mark.parametrize("arch", ["qwen2.5-14b", "qwen3-8b"])
+def test_init_packed_params_matches_two_step_path(arch):
+    """The per-layer seeded builder gives, leaf for leaf and bit for bit,
+    the packed tree of init_params followed by prequantize_params."""
+    from repro.configs.registry import smoke_config
+    from repro.serve import init_packed_params
+    cfg = smoke_config(arch)
+    want = prequantize_params(init_params(KEY, cfg), cfg)
+    got = init_packed_params(KEY, cfg)
+    flat_w, tree_w = jax.tree_util.tree_flatten_with_path(want)
+    flat_g, tree_g = jax.tree_util.tree_flatten_with_path(got)
+    assert tree_g == tree_w
+    for (path, a), (_, b) in zip(flat_w, flat_g):
+        assert a.dtype == b.dtype, jax.tree_util.keystr(path)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=jax.tree_util.keystr(path))
+
+
+def _load_chip_smoke():
+    import importlib.util
+    import pathlib
+    path = pathlib.Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_chip_smoke_refuses_cpu(capsys):
+    """The smoke run has no CPU path: on a CPU backend it exits non-zero
+    before any phase and prints no result line."""
+    assert jax.devices()[0].platform == "cpu"
+    smoke = _load_chip_smoke()
+    with pytest.raises(SystemExit) as exc:
+        smoke.require_tpu()
+    assert exc.value.code not in (0, None)
+    with pytest.raises(SystemExit) as exc:
+        smoke.main([])
+    assert exc.value.code not in (0, None)
+    assert '"ok"' not in capsys.readouterr().out
+
+
+def test_compile_cache_dir(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR, where set, is left to JAX; otherwise the
+    cache goes to the same fixed directory inside the checkout."""
+    import pathlib
+    from jax.experimental.compilation_cache import compilation_cache
+    from repro.launch.compile_cache import CACHE_DIR, use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert use_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert use_compile_cache() == use_compile_cache() == str(CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(CACHE_DIR)
+        repo = pathlib.Path(__file__).resolve().parents[1]
+        assert CACHE_DIR == repo / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        compilation_cache.reset_cache()
+
+
 def test_load_rejects_dense_checkpoint(packed_model, tmp_path):
     cfg, params, _ = packed_model
     from repro.checkpoint import save_state
