@@ -193,7 +193,8 @@ def attention_forward(
     """Full-sequence attention (train / prefill). Returns (out, (k, v))."""
     q, k, v = _project_qkv(p, x, cfg, positions, quant)
     w = jnp.int32(2 ** 30) if window is None else window
-    out = _chunked_attention(q, k, v, positions, positions, cfg, w)
+    with jax.named_scope("attention"):
+        out = _chunked_attention(q, k, v, positions, positions, cfg, w)
     b, s = x.shape[:2]
     out = out.reshape(b, s, -1).astype(x.dtype)
     out = constrain(out, ("batch", "seq", "q_dim"))
@@ -227,11 +228,13 @@ def _attend_one(q, k_new, v_new, out_dtype, cfg, cache, index, window,
     (per-slot caches). Returns (ctx (B,1,nh*hd) in ``out_dtype`` — the
     pre-``wo`` attention context, new cache dict). The cache is
     sequence-sharded ('kv_seq' -> TP axis); the softmax reduction over W
-    crosses shards (GSPMD ring-attention-equivalent)."""
+    crosses shards (GSPMD ring-attention-equivalent).
+
+    The write (and a quantized page's encode and decode) runs under the
+    ``kv_cache`` named scope, the attention proper under ``attention``."""
     b = q.shape[0]
     quantized_kv = cfg.kv_quant != "none"
     w = (cache["k"]["codes"] if quantized_kv else cache["k"]).shape[1]
-    nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     per_slot = jnp.ndim(index) == 1
     if valid is not None and not per_slot:
         raise ValueError("masked cache writes need per-slot caches")
@@ -240,48 +243,59 @@ def _attend_one(q, k_new, v_new, out_dtype, cfg, cache, index, window,
     else:
         pos_new = jnp.full((b, 1), index, dtype=jnp.int32)
 
-    slot = jnp.mod(index, w)                       # scalar or (B,)
-    if quantized_kv:
-        from .kvquant import kv_decode, kv_encode, kv_page_write
-        kc, vc = {}, {}
-        for name, new, store in (("k", k_new, kc), ("v", v_new, vc)):
-            enc = kv_encode(new, cfg.kv_quant)
-            if per_slot:
-                upd = kv_page_write(cache[name], enc, slot, valid)
-            else:
-                upd = {key: jax.lax.dynamic_update_slice(
-                    cache[name][key], enc[key], (0, slot, 0, 0))
-                    for key in enc}
-            for key in upd:
-                store[key] = constrain(
-                    upd[key], ("batch", "kv_seq", "kv_heads", None))
-        k = kv_decode(kc, cfg.kv_quant)
-        v = kv_decode(vc, cfg.kv_quant)
-    else:
-        if per_slot:
-            k = _masked_rows(
-                cache["k"], jax.vmap(_row_update)(cache["k"], k_new, slot),
-                valid)
-            v = _masked_rows(
-                cache["v"], jax.vmap(_row_update)(cache["v"], v_new, slot),
-                valid)
+    with jax.named_scope("kv_cache"):
+        slot = jnp.mod(index, w)                       # scalar or (B,)
+        if quantized_kv:
+            from .kvquant import kv_decode, kv_encode, kv_page_write
+            kc, vc = {}, {}
+            for name, new, store in (("k", k_new, kc), ("v", v_new, vc)):
+                enc = kv_encode(new, cfg.kv_quant)
+                if per_slot:
+                    upd = kv_page_write(cache[name], enc, slot, valid)
+                else:
+                    upd = {key: jax.lax.dynamic_update_slice(
+                        cache[name][key], enc[key], (0, slot, 0, 0))
+                        for key in enc}
+                for key in upd:
+                    store[key] = constrain(
+                        upd[key], ("batch", "kv_seq", "kv_heads", None))
+            k = kv_decode(kc, cfg.kv_quant)
+            v = kv_decode(vc, cfg.kv_quant)
         else:
-            k = jax.lax.dynamic_update_slice(
-                cache["k"], k_new.astype(cache["k"].dtype), (0, slot, 0, 0))
-            v = jax.lax.dynamic_update_slice(
-                cache["v"], v_new.astype(cache["v"].dtype), (0, slot, 0, 0))
-        kc, vc = k, v
-    if per_slot:
-        pos = _masked_rows(
-            cache["pos"], jax.vmap(_row_update)(cache["pos"], pos_new, slot),
-            valid)
-    else:
-        pos = jax.lax.dynamic_update_slice(
-            cache["pos"], jnp.full((1,), index, jnp.int32), (slot,))
+            if per_slot:
+                k = _masked_rows(cache["k"], jax.vmap(_row_update)(
+                    cache["k"], k_new, slot), valid)
+                v = _masked_rows(cache["v"], jax.vmap(_row_update)(
+                    cache["v"], v_new, slot), valid)
+            else:
+                k = jax.lax.dynamic_update_slice(
+                    cache["k"], k_new.astype(cache["k"].dtype),
+                    (0, slot, 0, 0))
+                v = jax.lax.dynamic_update_slice(
+                    cache["v"], v_new.astype(cache["v"].dtype),
+                    (0, slot, 0, 0))
+            kc, vc = k, v
+        if per_slot:
+            pos = _masked_rows(cache["pos"], jax.vmap(_row_update)(
+                cache["pos"], pos_new, slot), valid)
+        else:
+            pos = jax.lax.dynamic_update_slice(
+                cache["pos"], jnp.full((1,), index, jnp.int32), (slot,))
     k = constrain(k, ("batch", "kv_seq", "kv_heads", None))
     v = constrain(v, ("batch", "kv_seq", "kv_heads", None))
 
     eff_w = jnp.int32(2 ** 30) if window is None else window
+    with jax.named_scope("attention"):
+        ctx = _attend_cache(q, k, v, pos, index, eff_w, out_dtype, cfg)
+    return ctx, {"k": kc, "v": vc, "pos": pos}
+
+
+def _attend_cache(q, k, v, pos, index, eff_w, out_dtype, cfg):
+    """Scores of one query row per slot against the whole cache page,
+    softmax over the valid entries, and the PV product: (B,1,nh*hd)."""
+    b = q.shape[0]
+    nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    per_slot = jnp.ndim(index) == 1
     # single-token scores over the whole cache: (B, nkv, g, W)
     g = nh // nkv
     qh = q.reshape(b, nkv, g, hd).astype(jnp.bfloat16)
@@ -296,8 +310,7 @@ def _attend_one(q, k_new, v_new, out_dtype, cfg, cache, index, window,
     probs = jax.nn.softmax(sc, axis=-1)
     out = einsum_f32acc("bkgw,bwkd->bkgd", probs.astype(jnp.bfloat16),
                         v.astype(jnp.bfloat16))
-    ctx = out.reshape(b, 1, nh * hd).astype(out_dtype)
-    return ctx, {"k": kc, "v": vc, "pos": pos}
+    return out.reshape(b, 1, nh * hd).astype(out_dtype)
 
 
 def attention_decode(

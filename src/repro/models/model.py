@@ -173,12 +173,15 @@ def _embed_in(params, cfg, batch):
 
 
 def _logits(params, cfg, h):
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    """Final norm and vocabulary projection, under the ``lm_head`` named
+    scope."""
     from .numerics import dot_f32acc
-    logits = dot_f32acc(h, head, (((h.ndim - 1,), (0,)), ((), ())))
-    logits = softcap(logits, cfg.final_softcap)
-    return constrain(logits, ("batch", "seq", "vocab"))
+    with jax.named_scope("lm_head"):
+        h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = dot_f32acc(h, head, (((h.ndim - 1,), (0,)), ((), ())))
+        logits = softcap(logits, cfg.final_softcap)
+        return constrain(logits, ("batch", "seq", "vocab"))
 
 
 # ---------------------------------------------------------------------------

@@ -178,12 +178,18 @@ def _serve_matmul(x: jax.Array, w: PackedTensor, dims) -> jax.Array:
     meta-mode reductions over the online-quantized activations, drained
     host-side via ``jax.debug.callback`` (asynchronous — no extra syncs on
     the launch); the ``metrics`` pillar counts which backend each GEMM
-    call site dispatched to, labeled by codec."""
+    call site dispatched to, labeled by codec.
+
+    The online quantizer runs under the ``act_quant`` named scope and the
+    GEMM (kernel or mirror) under ``serve_gemm``: names in the HLO's
+    ``op_name`` metadata that a device trace attributes time to. They
+    change no op."""
     from repro import obs
     from .numerics import dot_f32acc
     codec = get_codec(w.codec)
     obs.quant_health.probe_act(x, site="serve_gemm", codec=codec.name)
-    xq = codec.fake_quant_act(x.astype(jnp.float32)).astype(jnp.bfloat16)
+    with jax.named_scope("act_quant"):
+        xq = codec.fake_quant_act(x.astype(jnp.float32)).astype(jnp.bfloat16)
     k = w.shape[0]
     n = math.prod(w.shape[1:])
     use_pallas = (serve_matmul_backend() == "pallas"
@@ -194,20 +200,16 @@ def _serve_matmul(x: jax.Array, w: PackedTensor, dims) -> jax.Array:
             "serve GEMM call sites traced, by dispatched backend").inc(
             backend="pallas" if use_pallas else "xla", codec=codec.name,
             k=k, n=n)
-    if use_pallas:
-        with obs.span("trace.serve_matmul", cat="trace", backend="pallas",
-                      codec=codec.name, k=k, n=n):
+    with jax.named_scope("serve_gemm"):
+        if use_pallas:
             streams = {name: w[name].reshape(w[name].shape[0], n)
                        for name in _tail_streams(w)}
             for name, s in w.streams.items():
                 streams.setdefault(name, s)            # per-tensor scalars
             out = codec.kernel(xq.reshape(-1, k), streams)
-        return out.reshape(*x.shape[:-1], *w.shape[1:]).astype(x.dtype)
-    with obs.span("trace.serve_matmul", cat="trace", backend="xla",
-                  codec=codec.name, k=k, n=n):
+            return out.reshape(*x.shape[:-1], *w.shape[1:]).astype(x.dtype)
         wd = decode_serving_weight(w)
-        out = dot_f32acc(xq.astype(wd.dtype), wd, dims).astype(x.dtype)
-    return out
+        return dot_f32acc(xq.astype(wd.dtype), wd, dims).astype(x.dtype)
 
 
 def quantized_matmul(x: jax.Array, w, quant: str, fmt: str = "m2xfp",

@@ -5,12 +5,22 @@
 ``chrome://tracing`` and Perfetto (https://ui.perfetto.dev) render as a
 nested timeline per thread — nesting falls out of wall-clock containment
 on the same tid, so ``serve.step`` > ``serve.phase.decode`` >
-``serve.kernel.dispatch`` stack visually without parent bookkeeping.
+``serve.launch.dispatch`` / ``serve.launch.wait`` stack visually without
+parent bookkeeping.
+
+Each span also opens a ``jax.profiler.TraceAnnotation`` of the same name,
+so a profile taken while the spans are on (``jax.profiler.start_trace``)
+holds them on its host plane, on the device trace's clock, next to the
+device's ops; with no profile running an annotation costs next to
+nothing. The device side carries names too: the model wraps its work in
+``jax.named_scope`` (``act_quant``, ``serve_gemm``, ``attention``,
+``kv_cache``, ``lm_head``), which land in each HLO op's ``op_name``
+metadata and so in the trace's per-op statistics (docs/observability.md).
 
 Gated by the ``trace`` pillar of ``REPRO_OBS`` (registry.enabled): when
-off, ``span`` yields without recording or reading the clock. Thread-safe:
-events append under a lock; tids are real thread idents so concurrent
-engine/trainer threads land on separate tracks.
+off, ``span`` yields without recording, annotating or reading the clock.
+Thread-safe: events append under a lock; tids are real thread idents so
+concurrent engine/trainer threads land on separate tracks.
 """
 from __future__ import annotations
 
@@ -37,24 +47,27 @@ class SpanTracer:
 
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "repro", **args):
-        """Context manager timing one span. ``args`` (str/num values) show
-        in the trace viewer's argument pane. No-op when the ``trace``
+        """Context manager timing one span, mirrored as a profiler
+        ``TraceAnnotation`` of the same name. ``args`` (str/num values)
+        show in the trace viewer's argument pane. No-op when the ``trace``
         pillar is off at entry."""
         if not enabled("trace"):
             yield
             return
-        t0 = time.perf_counter_ns()
-        try:
-            yield
-        finally:
-            t1 = time.perf_counter_ns()
-            ev = {"name": name, "cat": cat, "ph": "X",
-                  "ts": t0 / 1e3, "dur": (t1 - t0) / 1e3,
-                  "pid": self._pid, "tid": threading.get_ident()}
-            if args:
-                ev["args"] = {k: v for k, v in args.items()}
-            with self._lock:
-                self._events.append(ev)
+        from jax.profiler import TraceAnnotation
+        with TraceAnnotation(name):
+            t0 = time.perf_counter_ns()
+            try:
+                yield
+            finally:
+                t1 = time.perf_counter_ns()
+                ev = {"name": name, "cat": cat, "ph": "X",
+                      "ts": t0 / 1e3, "dur": (t1 - t0) / 1e3,
+                      "pid": self._pid, "tid": threading.get_ident()}
+                if args:
+                    ev["args"] = {k: v for k, v in args.items()}
+                with self._lock:
+                    self._events.append(ev)
 
     def instant(self, name: str, cat: str = "repro", **args) -> None:
         """Zero-duration marker (admissions, evictions, EOS hits)."""

@@ -55,6 +55,8 @@ from .scheduler import AdmissionError, Request, SlotScheduler
 
 # TTFT is quantized in engine steps; buckets cover 1..256-step prompts
 _TTFT_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256)
+# the parts of a step's host time ServeStats.note_step keeps apart
+_SPLIT = ("dispatch_s", "wait_s", "guard_s")
 
 __all__ = ["ServeEngine", "ServeStats", "tree_nbytes"]
 
@@ -80,6 +82,11 @@ class ServeStats:
     quarantined: int = 0           # requests evicted for poisoned state
     expired: int = 0               # requests past their deadline
     shed: int = 0                  # requests rejected at admission
+    # where each step's host time went (always on: a few clock reads a step)
+    dispatch_s: float = 0.0        # enqueueing launches (the jitted call)
+    wait_s: float = 0.0            # blocked on the logits' copy to the host
+    guard_s: float = 0.0           # the guard's drain and fault containment
+    longest_step: Optional[dict] = None    # see note_step
 
     @property
     def tokens_per_sec(self) -> float:
@@ -104,6 +111,22 @@ class ServeStats:
         if not self.steps:
             return 0.0
         return self.slot_steps / (self.steps * self.n_slots)
+
+    def note_step(self, wall_s: float, dispatch_s: float, wait_s: float,
+                  guard_s: float) -> None:
+        """Add one step's split to the totals, and keep it as
+        ``longest_step`` (``wall_s``, ``dispatch_s``, ``wait_s``,
+        ``guard_s`` and the ``rest_s`` of admission, planning, sampling
+        and routing) when no step since it was last ``None`` took
+        longer."""
+        self.dispatch_s += dispatch_s
+        self.wait_s += wait_s
+        self.guard_s += guard_s
+        if self.longest_step is None or wall_s > self.longest_step["wall_s"]:
+            self.longest_step = {
+                "wall_s": wall_s, "dispatch_s": dispatch_s,
+                "wait_s": wait_s, "guard_s": guard_s,
+                "rest_s": wall_s - dispatch_s - wait_s - guard_s}
 
     def to_dict(self) -> dict:
         """Every field plus every derived property, as plain floats/ints —
@@ -218,6 +241,7 @@ class ServeEngine:
             n_slots, max_queue=max_queue,
             max_prompt_len=None if cfg.sliding_window else max_len)
         self.stats = ServeStats(n_slots=n_slots)
+        self._split = dict.fromkeys(_SPLIT, 0.0)   # this step's host time
 
         if verify_weights:
             self.params, repairs = _guard.verify_packed_tree(
@@ -347,12 +371,17 @@ class ServeEngine:
         for slot, req in self.scheduler.active.items():
             if req.phase == "prefill":
                 self._tokens[slot, 0] = req.prompt[req.consumed]
-        with obs.span("serve.kernel.dispatch", kind="decode_step",
+        t0 = time.perf_counter()
+        with obs.span("serve.launch.dispatch", kind="decode_step",
                       slots=self.n_slots):
             logits, self.caches = self._step(
                 self.params, {"tokens": jnp.asarray(self._tokens)},
                 self.caches, jnp.asarray(self._index))
+        t1 = time.perf_counter()
+        with obs.span("serve.launch.wait"):
             out = np.asarray(logits[:, -1]).astype(np.float32)
+        self._split["dispatch_s"] += t1 - t0
+        self._split["wait_s"] += time.perf_counter() - t1
         return out
 
     def _launch_prefill(self, chunks) -> np.ndarray:
@@ -371,12 +400,17 @@ class ServeEngine:
                 toks[slot, :c] = req.prompt[req.consumed:req.consumed + c]
             else:
                 toks[slot, 0] = self._tokens[slot, 0]
-        with obs.span("serve.kernel.dispatch", kind="prefill_chunk",
+        t0 = time.perf_counter()
+        with obs.span("serve.launch.dispatch", kind="prefill_chunk",
                       slots=self.n_slots, tokens=int(lens.sum())):
             logits, self.caches = self._prefill(
                 self.params, {"tokens": jnp.asarray(toks)}, self.caches,
                 jnp.asarray(self._index), jnp.asarray(lens))
+        t1 = time.perf_counter()
+        with obs.span("serve.launch.wait"):
             lg = np.asarray(logits).astype(np.float32)    # (B, T, V)
+        self._split["dispatch_s"] += t1 - t0
+        self._split["wait_s"] += time.perf_counter() - t1
         return lg[np.arange(self.n_slots), np.maximum(lens - 1, 0)]
 
     def step(self) -> int:
@@ -388,8 +422,12 @@ class ServeEngine:
         retry budget, or quarantines blew ``max_quarantines``)."""
         if self.guard:
             self.guard.check_alive()
+        self._split = dict.fromkeys(_SPLIT, 0.0)
+        t0 = time.perf_counter()
         with obs.span("serve.step", step=self.stats.steps):
-            return self._step_inner()
+            finished = self._step_inner()
+        self.stats.note_step(time.perf_counter() - t0, **self._split)
+        return finished
 
     def _guarded_launch(self, fn, chunks) -> np.ndarray:
         """Run a launch with the guard's transient-failure retry policy.
@@ -483,7 +521,10 @@ class ServeEngine:
             sampled_from = self._guarded_launch(launch, chunks)
         dt = time.perf_counter() - t0
         if self.guard:
-            self._contain_faults(chunks, sampled_from)
+            t1 = time.perf_counter()
+            with obs.span("serve.guard.drain"):
+                self._contain_faults(chunks, sampled_from)
+            self._split["guard_s"] += time.perf_counter() - t1
         with obs.span("serve.sample"):
             sampled = self.sample_fn(sampled_from)
 

@@ -223,13 +223,15 @@ def test_engine_emits_metrics_and_trace(monkeypatch, tmp_path):
     assert "repro_quant_reencode_drift" in text
     assert "repro_quant_meta_total" in text
 
-    # acceptance: nested spans step -> phase -> kernel dispatch
+    # acceptance: nested spans step -> phase -> launch dispatch and wait,
+    # step -> guard drain
     evs = obs.tracer().events()
     byname = {}
     for e in evs:
         byname.setdefault(e["name"], []).append(e)
     for required in ("serve.run", "serve.step", "serve.plan",
-                     "serve.kernel.dispatch", "serve.weight_health",
+                     "serve.launch.dispatch", "serve.launch.wait",
+                     "serve.guard.drain", "serve.weight_health",
                      "serve.sample"):
         assert required in byname, f"missing span {required}"
     assert ("serve.phase.decode" in byname or
@@ -240,12 +242,19 @@ def test_engine_emits_metrics_and_trace(monkeypatch, tmp_path):
                 inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
                 + 1e-6 and outer["tid"] == inner["tid"])
 
-    disp = byname["serve.kernel.dispatch"][0]
     phases = (byname.get("serve.phase.decode", []) +
               byname.get("serve.phase.prefill", []))
-    phase = next(p for p in phases if contains(p, disp))
-    step = next(s for s in byname["serve.step"] if contains(s, phase))
-    assert contains(step, phase) and contains(phase, disp)
+    for disp, wait in zip(byname["serve.launch.dispatch"],
+                          byname["serve.launch.wait"]):
+        phase = next(p for p in phases if contains(p, disp))
+        assert contains(phase, wait)
+        assert disp["ts"] + disp["dur"] <= wait["ts"] + 1e-6
+        step = next(s for s in byname["serve.step"] if contains(s, phase))
+        assert contains(step, phase)
+    assert len(byname["serve.launch.dispatch"]) == len(phases)
+    for drain in byname["serve.guard.drain"]:
+        assert any(contains(s, drain) for s in byname["serve.step"])
+        assert not any(contains(p, drain) for p in phases)
 
     # the trace file is a loadable Chrome trace
     path = str(tmp_path / "trace.json")
@@ -273,6 +282,106 @@ def test_obs_off_bit_identical_tokens(monkeypatch):
 
     assert out_off == out_on
     assert "repro_serve_steps_total" in obs.registry().render_prometheus()
+
+
+def test_span_mirrors_a_trace_annotation_when_on(monkeypatch):
+    """With the trace pillar on, each span opens a profiler annotation of
+    its own name around the timed body; with it off nothing is called."""
+    import jax.profiler
+    opened = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(("enter", self.name))
+
+        def __exit__(self, *exc):
+            opened.append(("exit", self.name))
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    with obs.span("t.off"):
+        pass
+    assert opened == [] and obs.tracer().events() == []
+
+    monkeypatch.setenv("REPRO_OBS", "trace")
+    with obs.span("t.outer"):
+        with obs.span("t.inner"):
+            opened.append(("body", "t.inner"))
+    assert opened == [("enter", "t.outer"), ("enter", "t.inner"),
+                      ("body", "t.inner"), ("exit", "t.inner"),
+                      ("exit", "t.outer")]
+    assert [e["name"] for e in obs.tracer().events()] == ["t.inner",
+                                                          "t.outer"]
+
+
+def test_servestats_split_fits_inside_each_step():
+    """The always-on split of a step's host time: dispatch, wait and guard
+    add up to no more than the step's wall time, the longest step is kept
+    with its rest, and the totals are the sums over steps."""
+    cfg = tiny_cfg()
+    eng = ServeEngine(tiny_packed(cfg), cfg, n_slots=2, max_len=32,
+                      prefill_chunk=4)
+    for p in PROMPTS:
+        eng.submit(p, 4)
+    walls, splits = [], []
+    note = eng.stats.note_step
+
+    def record(wall_s, **split):
+        walls.append(wall_s)
+        splits.append(split)
+        note(wall_s, **split)
+
+    eng.stats.note_step = record
+    while eng.scheduler.has_work:
+        eng.step()
+    assert splits and all(sum(sp.values()) <= w
+                          for w, sp in zip(walls, splits))
+    assert all(sp["dispatch_s"] > 0 and sp["wait_s"] > 0
+               and sp["guard_s"] > 0 for sp in splits)
+    s = eng.stats
+    for part in ("dispatch_s", "wait_s", "guard_s"):
+        assert getattr(s, part) == pytest.approx(
+            sum(sp[part] for sp in splits))
+    longest = s.longest_step
+    assert longest["wall_s"] == max(walls)
+    assert longest["rest_s"] >= 0
+    assert longest["rest_s"] == pytest.approx(
+        longest["wall_s"] - longest["dispatch_s"] - longest["wait_s"]
+        - longest["guard_s"])
+    json.dumps(s.to_dict())
+    s.longest_step = None
+    s.note_step(0.5, dispatch_s=0.1, wait_s=0.2, guard_s=0.05)
+    assert s.longest_step["rest_s"] == pytest.approx(0.15)
+
+
+SCOPES = {"act_quant", "serve_gemm", "attention", "kv_cache", "lm_head"}
+
+
+@pytest.mark.parametrize("kv_quant", ["none", "m2xfp"])
+@pytest.mark.parametrize("launch", ["decode_step", "prefill_chunk"])
+def test_model_step_carries_the_named_scopes(launch, kv_quant):
+    """Each of the five named scopes a device trace attributes time to
+    appears in the compiled launch's ``op_name`` metadata."""
+    import re
+    from repro.models import model
+    cfg = tiny_cfg(kv_quant=kv_quant)
+    caches = model.init_caches(cfg, 2, 32, per_slot=True)
+    index = jnp.zeros((2,), jnp.int32)
+    if launch == "decode_step":
+        fn = jax.jit(lambda p, b, c, i: model.decode_step(p, cfg, b, c, i))
+        args = ({"tokens": jnp.zeros((2, 1), jnp.int32)}, caches, index)
+    else:
+        fn = jax.jit(lambda p, b, c, i, l: model.prefill_chunk(
+            p, cfg, b, c, i, l))
+        args = ({"tokens": jnp.zeros((2, 4), jnp.int32)}, caches, index,
+                jnp.array([4, 1], jnp.int32))
+    text = fn.lower(tiny_packed(cfg), *args).compile().as_text()
+    found = set()
+    for op_name in re.findall(r'op_name="([^"]*)"', text):
+        found.update(SCOPES.intersection(op_name.split("/")))
+    assert found == SCOPES
 
 
 def test_obs_off_records_nothing():
