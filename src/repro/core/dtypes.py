@@ -130,21 +130,56 @@ def round_to_grid(x: jax.Array, spec: FloatSpec, saturate: bool = True) -> jax.A
 
 
 # --- code <-> value conversions (needed for the bias-clamp metadata encoding) ---
+#
+# Elementwise bit arithmetic only (bitcast, shifts, compares, selects): no
+# table gather, no search loop. XLA fuses these into the quantizer's passes,
+# and Mosaic lowers them to the VPU, so the Pallas kernels use the same four
+# functions (via ``repro.kernels.bitmath``).
+
+
+def _exponent_field(v: jax.Array):
+    """(f32 bits, unbiased exponent) of f32 ``v``."""
+    bits = jax.lax.bitcast_convert_type(v, jnp.int32)
+    return bits, ((bits >> 23) & 0xFF) - 127
+
 
 def fp4_value_to_code(v: jax.Array) -> jax.Array:
-    """Magnitude (exact grid value) -> 3-bit E2M1 code. v must be on-grid, >=0."""
-    # searchsorted on the static 8-entry grid; exact because v is on-grid.
-    return jnp.searchsorted(FP4_MAG_VALUES, v.astype(jnp.float32)).astype(jnp.int32)
+    """Magnitude (exact grid value, >= 0) -> 3-bit E2M1 code.
+
+    Normals (v >= 1) read the code from the exponent and the top mantissa
+    bit. A value above the grid's max (inf and NaN too) gives 8, where a
+    search of the grid would put it."""
+    v = v.astype(jnp.float32)
+    bits, e = _exponent_field(v)
+    code = ((e + 1) << 1) | ((bits >> 22) & 1)
+    code = jnp.where(v == 0.0, 0, jnp.where(v < 1.0, 1, code))
+    return jnp.where(v <= FP4_E2M1.max_value, code, 8).astype(jnp.int32)
 
 
 def fp4_code_to_value(c: jax.Array) -> jax.Array:
-    return FP4_MAG_VALUES[jnp.clip(c, 0, 7)]
+    """3-bit E2M1 code (0..7) -> grid value (f32):
+    c==0 -> 0, c==1 -> 0.5, else 2^((c>>1)-1) * (1 + (c&1)/2)."""
+    c = c.astype(jnp.int32)
+    normal = exp2int((c >> 1) - 1) * (1.0 + 0.5 * (c & 1).astype(jnp.float32))
+    return jnp.where(c == 0, 0.0, jnp.where(c == 1, 0.5, normal))
 
 
 def fp6_value_to_code(v: jax.Array) -> jax.Array:
-    """Magnitude (exact grid value) -> 5-bit E2M3 code. v must be on-grid, >=0."""
-    return jnp.searchsorted(FP6_MAG_VALUES, v.astype(jnp.float32)).astype(jnp.int32)
+    """Magnitude (exact grid value, >= 0) -> 5-bit E2M3 code.
+
+    Subnormals (v < 1) are k/8; normals read the exponent and the top three
+    mantissa bits. Above the grid's max (inf and NaN too) gives 32."""
+    v = v.astype(jnp.float32)
+    bits, e = _exponent_field(v)
+    code = ((e + 1) << 3) | ((bits >> 20) & 7)
+    code = jnp.where(v < 1.0, (v * 8.0).astype(jnp.int32), code)
+    return jnp.where(v <= FP6_E2M3.max_value, code, 32).astype(jnp.int32)
 
 
 def fp6_code_to_value(c: jax.Array) -> jax.Array:
-    return FP6_MAG_VALUES[jnp.clip(c, 0, 31)]
+    """5-bit E2M3 code (0..31) -> grid value (f32):
+    e=c>>3, m=c&7: e==0 -> m/8, else 2^(e-1) * (1 + m/8)."""
+    c = c.astype(jnp.int32)
+    e = c >> 3
+    m = (c & 7).astype(jnp.float32)
+    return jnp.where(e == 0, m / 8.0, exp2int(e - 1) * (1.0 + m / 8.0))

@@ -80,12 +80,14 @@ def elem_em_encode_parts(xg: jax.Array, s: jax.Array, subgroup: int):
 
     c4 = fp4_value_to_code(jnp.abs(q4s))                   # 3-bit codes
     # Step 3-4: top-1 by FP4 magnitude, lowest index on ties. Written as
-    # max + first-match cumsum + masked reduce (no argmax/gather/one_hot):
-    # every op is elementwise or a small-axis reduction, so XLA fuses the
-    # whole online encoder into a few passes (vital for the serve path).
+    # max + min-index-of-max + masked reduce (no argmax/gather/one_hot/
+    # cumsum): every op is elementwise or a small-axis reduction, so XLA
+    # fuses the whole online encoder into a few passes (vital for the serve
+    # path; a cumsum lowers to reduce_window).
     c4_top = jnp.max(c4, axis=-1)                          # (..., ng, n_sub)
-    is_max = c4 == c4_top[..., None]
-    top1 = is_max & (jnp.cumsum(is_max.astype(jnp.int32), axis=-1) == 1)
+    idx = jnp.arange(subgroup, dtype=jnp.int32)
+    first = jnp.min(jnp.where(c4 == c4_top[..., None], idx, subgroup), axis=-1)
+    top1 = idx == first[..., None]
     x_orig = jnp.sum(jnp.where(top1, xss, 0.0), axis=-1)
 
     # Step 5: requantize the original (scaled) value to FP6 E2M3.

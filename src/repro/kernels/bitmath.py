@@ -1,11 +1,14 @@
-"""Mosaic-friendly bit arithmetic for FP4/FP6 code <-> value conversion.
+"""Mosaic-friendly bit arithmetic for the Pallas kernels.
 
-Pallas TPU kernels cannot rely on gathers/LUTs or ``frexp``; these helpers
-use only elementwise integer/float ops (bitcast, shifts, selects) that lower
-to the VPU. They are the arithmetic equivalent of the paper's 16-entry
-decode LUT (Fig. 10).
+Pallas TPU kernels cannot rely on gathers/LUTs or ``frexp``; every helper
+here uses only elementwise integer/float ops (bitcast, shifts, selects) that
+lower to the VPU — the arithmetic equivalent of the paper's 16-entry decode
+LUT (Fig. 10).
 
-Code conventions (match core/):
+Code conventions: ``repro.core.dtypes`` is the one home of ``exp2int`` and
+the FP4/FP6 code <-> value conversions (the XLA quantizer runs the same
+functions); this module re-exports them and adds the exponent-field log2 and
+RTNE rounding.
   FP4 sign-magnitude: bit3 = sign, bits2..0 = E2M1 magnitude code
   E2M1 magnitude code c: c==0 -> 0, c==1 -> 0.5, else 2^((c>>1)-1)*(1+(c&1)/2)
   E2M3 magnitude code c: e=c>>3, m=c&7: e==0 -> m/8, else 2^(e-1)*(1+m/8)
@@ -15,16 +18,15 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.core.dtypes import (
+    exp2int, fp4_code_to_value, fp4_value_to_code, fp6_code_to_value,
+    fp6_value_to_code,
+)
+
 __all__ = [
-    "exp2i", "floor_log2_bits", "fp4_mag_from_code", "fp4_code_from_mag",
-    "fp6_mag_from_code", "fp6_code_from_mag", "rtne_fp4", "rtne_fp6",
+    "exp2int", "floor_log2_bits", "fp4_code_to_value", "fp4_value_to_code",
+    "fp6_code_to_value", "fp6_value_to_code", "rtne_fp4", "rtne_fp6",
 ]
-
-
-def exp2i(e: jax.Array) -> jax.Array:
-    """2^e for integer e in [-126, 127], via exponent-field construction."""
-    bits = (jnp.clip(e, -126, 127).astype(jnp.int32) + 127) << 23
-    return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
 
 def floor_log2_bits(x: jax.Array) -> jax.Array:
@@ -33,54 +35,14 @@ def floor_log2_bits(x: jax.Array) -> jax.Array:
     return ((bits >> 23) & 0xFF) - 127
 
 
-def fp4_mag_from_code(c: jax.Array) -> jax.Array:
-    """E2M1 magnitude code (int, 0..7) -> grid value (f32)."""
-    c = c.astype(jnp.int32)
-    e = c >> 1
-    m = (c & 1).astype(jnp.float32)
-    normal = exp2i(e - 1) * (1.0 + 0.5 * m)
-    return jnp.where(c == 0, 0.0, jnp.where(c == 1, 0.5, normal))
-
-
-def fp4_code_from_mag(v: jax.Array) -> jax.Array:
-    """Exact on-grid E2M1 magnitude -> code, from f32 bit fields."""
-    v = v.astype(jnp.float32)
-    bits = jax.lax.bitcast_convert_type(v, jnp.int32)
-    e = ((bits >> 23) & 0xFF) - 127           # true exponent
-    m1 = (bits >> 22) & 1                     # top mantissa bit
-    code = ((e + 1) << 1) | m1                # normals: v >= 1
-    return jnp.where(v == 0.0, 0, jnp.where(v < 1.0, 1, code)).astype(jnp.int32)
-
-
-def fp6_mag_from_code(c: jax.Array) -> jax.Array:
-    """E2M3 magnitude code (int, 0..31) -> grid value (f32)."""
-    c = c.astype(jnp.int32)
-    e = c >> 3
-    m = (c & 7).astype(jnp.float32)
-    sub = m / 8.0
-    normal = exp2i(e - 1) * (1.0 + m / 8.0)
-    return jnp.where(e == 0, sub, normal)
-
-
-def fp6_code_from_mag(v: jax.Array) -> jax.Array:
-    """Exact on-grid E2M3 magnitude -> code, from f32 bit fields."""
-    v = v.astype(jnp.float32)
-    bits = jax.lax.bitcast_convert_type(v, jnp.int32)
-    e = ((bits >> 23) & 0xFF) - 127
-    m3 = (bits >> 20) & 7                     # top 3 mantissa bits
-    code = ((e + 1) << 3) | m3                # normals: v >= 1
-    sub_code = (v * 8.0).astype(jnp.int32)    # subnormals: exact k/8
-    return jnp.where(v < 1.0, sub_code, code).astype(jnp.int32)
-
-
 def _rtne_grid(x: jax.Array, man_bits: int, emin: int, emax: int,
                maxval: float) -> jax.Array:
     """RTNE onto a mini-float grid using only VPU-friendly ops."""
     x = x.astype(jnp.float32)
     ax = jnp.abs(x)
-    e = floor_log2_bits(jnp.maximum(ax, exp2i(jnp.full(ax.shape, emin, jnp.int32))))
+    e = floor_log2_bits(jnp.maximum(ax, exp2int(jnp.full(ax.shape, emin, jnp.int32))))
     e = jnp.clip(e, emin, emax)
-    step = exp2i(e - man_bits)
+    step = exp2int(e - man_bits)
     q = jnp.round(ax / step) * step
     q = jnp.minimum(q, maxval)
     return jnp.sign(x) * q

@@ -27,7 +27,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .bitmath import exp2i, fp4_code_from_mag, fp4_mag_from_code, fp6_mag_from_code
+from .bitmath import (
+    exp2int, fp4_code_to_value, fp4_value_to_code, fp6_code_to_value,
+)
 
 GROUP = 32
 SUBGROUP = 8
@@ -48,7 +50,7 @@ def _decode_codes(codes_u8: jax.Array, bk: int):
     lo = pg & 0xF
     hi = pg >> 4
     c = jnp.concatenate([lo, hi], axis=1).reshape(bk, n)   # natural K order
-    mag = fp4_mag_from_code(c & 7)
+    mag = fp4_code_to_value(c & 7)
     return mag, (c & 8) != 0
 
 
@@ -74,7 +76,7 @@ def _decode_w_sgem(wc_ref, ws_ref, wm_ref, bk: int) -> jax.Array:
     """Full Sg-EM weight decode -> bf16 (bk, bn)."""
     mag, neg = _decode_codes(wc_ref[...], bk)
     scale = _expand_groups(
-        exp2i(ws_ref[...].astype(jnp.int32) - 127), bk)
+        exp2int(ws_ref[...].astype(jnp.int32) - 127), bk)
     mult = 1.0 + _expand_subgroup_meta(wm_ref[...], bk).astype(jnp.float32) / 4.0
     w = mag * mult * scale
     return jnp.where(neg, -w, w).astype(jnp.bfloat16)
@@ -88,7 +90,7 @@ def _decode_x_elem_em(xc_ref, xs_ref, xm_ref, bk: int) -> jax.Array:
     Decode Unit."""
     bm = xc_ref.shape[-1]
     mag, neg = _decode_codes(xc_ref[...], bk)
-    c4 = fp4_code_from_mag(mag)
+    c4 = fp4_value_to_code(mag)
     c4s = c4.reshape(bk // GROUP, N_SUB, SUBGROUP, bm)
     cmax = jnp.max(c4s, axis=2, keepdims=True)
     is_max = c4s == cmax
@@ -99,11 +101,11 @@ def _decode_x_elem_em(xc_ref, xs_ref, xm_ref, bk: int) -> jax.Array:
         [(xm >> (2 * j)) & 0x3 for j in range(N_SUB)], axis=1
     )[:, :, None, :]                                         # (bk/32,4,1,bm)
     c6 = jnp.maximum((cmax << 2) | meta, 1) - 1
-    v6 = fp6_mag_from_code(c6)
+    v6 = fp6_code_to_value(c6)
     vals = jnp.where(top1, jnp.broadcast_to(v6, c4s.shape),
                      mag.reshape(c4s.shape)).reshape(bk, bm)
     scale = _expand_groups(
-        exp2i(xs_ref[...].astype(jnp.int32) - 127), bk)
+        exp2int(xs_ref[...].astype(jnp.int32) - 127), bk)
     x = vals * scale
     return jnp.where(neg, -x, x).astype(jnp.bfloat16)
 

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .bitmath import (
-    exp2i, floor_log2_bits, fp4_code_from_mag, fp6_code_from_mag,
+    exp2int, floor_log2_bits, fp4_value_to_code, fp6_value_to_code,
     rtne_fp4, rtne_fp6,
 )
 
@@ -39,11 +39,11 @@ def _quantize_kernel(x_ref, codes_ref, scales_ref, meta_ref, *, bk: int):
     e = floor_log2_bits(jnp.maximum(amax, 1e-30)) - 2           # log2(amax/4)
     e = jnp.where(amax == 0, 0, e)
     e = jnp.clip(e, -127, 127)
-    s = exp2i(e)
+    s = exp2int(e)
     xs = xg / s
     q4 = rtne_fp4(xs)                                           # FP4 values
     mag4 = jnp.abs(q4)
-    c4 = fp4_code_from_mag(mag4)
+    c4 = fp4_value_to_code(mag4)
 
     # Stage 2 — top-1 per subgroup (lowest index on ties), FP6 refine,
     # bias-clamp encode, pack.
@@ -53,7 +53,7 @@ def _quantize_kernel(x_ref, codes_ref, scales_ref, meta_ref, *, bk: int):
         jnp.cumsum((c4s == cmax).astype(jnp.int32), axis=2) == 1)
     xss = xs.reshape(c4s.shape)
     x_top = jnp.sum(jnp.where(first, xss, 0.0), axis=2)         # (G, 4, bm)
-    c6 = fp6_code_from_mag(jnp.abs(rtne_fp6(x_top)))
+    c6 = fp6_value_to_code(jnp.abs(rtne_fp6(x_top)))
     rmin = (cmax[..., 0, :] << 2)
     meta2 = jnp.clip(c6 + 1, rmin, rmin | 3) & 3                # (G, 4, bm)
     meta_byte = (

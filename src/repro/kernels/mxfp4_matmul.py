@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .bitmath import exp2i
+from .bitmath import exp2int
 from .m2xfp_matmul import GROUP, _decode_codes, _expand_groups
 
 DEFAULT_BM = 128
@@ -28,7 +28,7 @@ def _mm_kernel(x_ref, wc_ref, ws_ref, o_ref, *, bk: int):
 
     mag, neg = _decode_codes(wc_ref[...], bk)
     scale = _expand_groups(
-        exp2i(ws_ref[...].astype(jnp.int32) - 127), bk)
+        exp2int(ws_ref[...].astype(jnp.int32) - 127), bk)
     w = (mag * scale)
     w = jnp.where(neg, -w, w).astype(jnp.bfloat16)
     acc = jax.lax.dot_general(

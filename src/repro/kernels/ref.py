@@ -1,8 +1,11 @@
-"""Pure-jnp oracles for every Pallas kernel (no pallas, no bit tricks).
+"""Pure-jnp oracles for every Pallas kernel (no pallas).
 
 Each ref decodes the packed streams with the `core` reference machinery and
 computes the GEMM in f32 via the same bf16 operand casting the kernels use,
-so kernel-vs-ref comparisons are exact up to f32 accumulation order.
+so kernel-vs-ref comparisons are exact up to f32 accumulation order. The
+code <-> value conversions are the same functions the kernels use
+(``repro.core.dtypes``); ``tests/test_formats.py`` checks them against a
+table oracle.
 """
 from __future__ import annotations
 
@@ -100,6 +103,6 @@ def m2xfp_qmatmul_ref(x_packed: dict, w_packed: dict) -> jax.Array:
 
 def m2xfp_quantize_ref(x_t: jax.Array) -> dict:
     """Oracle for the quantize kernel: (K, M) -> packed streams, via the
-    core (LUT/searchsorted-based) Elem-EM encoder."""
+    core Elem-EM encoder (the XLA quantizer of the serve path)."""
     from .layout import pack_x_elem_em
     return pack_x_elem_em(x_t.T)

@@ -86,6 +86,120 @@ def test_top1_lowest_index_tiebreak():
     assert float(oh[0]) == 1.0 and float(jnp.sum(oh)) == 1.0
 
 
+# --- table oracle for the bit-arithmetic conversions and the top-1 pick ---
+
+_FP4_GRID = np.asarray([0, .5, 1, 1.5, 2, 3, 4, 6], np.float32)
+_FP6_GRID = np.asarray([m / 8 for m in range(8)] + [
+    2.0 ** (e - 1) * (1 + m / 8) for e in (1, 2, 3) for m in range(8)],
+    np.float32)
+
+
+def _jax_sign(x):
+    """``jnp.sign``: +-1, and a zero keeps its own sign."""
+    return np.where(x == 0, x, np.sign(x)).astype(np.float32)
+
+
+def _rtne_table(x, grid):
+    """Round |x| to the nearest grid value, ties to the even code,
+    saturating at the top; the sign is put back as ``round_to_grid`` does."""
+    a = np.abs(x).astype(np.float64)
+    hi = np.clip(np.searchsorted(grid, a), 1, len(grid) - 1)
+    lo = hi - 1
+    dlo, dhi = a - grid[lo], grid[hi] - a
+    pick = np.where((dhi < dlo) | ((dhi == dlo) & (hi % 2 == 0)), hi, lo)
+    return _jax_sign(x) * grid[pick]
+
+
+def _oracle_quantize_act(x, group=32, subgroup=8):
+    """Elem-EM-top1 over the floor-rule E8M0 scale, from the tables alone:
+    ``searchsorted`` codes, ``argmax`` for the first maximum, table decode."""
+    xg = x.astype(np.float32).reshape(*x.shape[:-1], -1, group)
+    amax = np.max(np.abs(xg), axis=-1, keepdims=True)
+    _, ex = np.frexp(np.maximum(amax, np.float32(1e-30)) / np.float32(4))
+    e = np.clip(np.where(amax == 0, 0, ex - 1), -126, 127)
+    s = np.ldexp(np.float32(1), e).astype(np.float32)
+    xs = (xg / s).reshape(*xg.shape[:-1], group // subgroup, subgroup)
+    q4 = _rtne_table(xs, _FP4_GRID)
+    c4 = np.searchsorted(_FP4_GRID, np.abs(q4))
+    top = np.argmax(c4, axis=-1)[..., None]
+    c4_top = np.take_along_axis(c4, top, axis=-1)
+    x_orig = np.take_along_axis(xs, top, axis=-1) + np.float32(0)
+    c6 = np.searchsorted(_FP6_GRID, np.abs(_rtne_table(x_orig, _FP6_GRID)))
+    meta = np.clip(c6 + 1, c4_top << 2, (c4_top << 2) | 3) & 3
+    v6 = _FP6_GRID[np.maximum((c4_top << 2) | meta, 1) - 1] * _jax_sign(x_orig)
+    is_top = np.arange(subgroup) == top
+    dq = np.where(is_top, v6, q4).reshape(xg.shape) * s
+    return dq.reshape(x.shape).astype(np.float32)
+
+
+def _act_input(kind, k, seed=0):
+    rng = np.random.default_rng([seed, k, len(kind)])
+    shape = (8, k)
+    if kind == "random":
+        return heavy_tailed(rng, shape)
+    if kind == "ties":                  # many equal FP4 codes per subgroup
+        pow2 = 2.0 ** rng.integers(-3, 4, (8, k // 32, 1))
+        return rng.integers(-4, 5, shape).astype(np.float32) * np.float32(
+            pow2).repeat(32, -1).reshape(shape)
+    if kind == "zeros":                 # zero columns and one zero group
+        x = heavy_tailed(rng, shape)
+        x[:, ::3] = 0
+        x[:, 64:96] = 0
+        return x
+    if kind == "all_zero":
+        return np.zeros(shape, np.float32)
+    if kind == "saturating":            # group max in [7.5, 8) x 2^n: > 6
+        x = rng.standard_normal(shape).astype(np.float32)
+        x[:, ::32] = rng.uniform(7.5, 8.0, (8, k // 32))
+        return x * np.float32(2.0 ** rng.integers(-20, 20, (8, 1)))
+    assert kind == "tiny_scale"         # 2^-100, or amax under the 1e-30
+    x = rng.standard_normal(shape).astype(np.float32)   # floor: all normal
+    return x * np.float32(2.0 ** -100 if k == 5120 else 1e-32)
+
+
+@pytest.mark.parametrize("case", ["conversions"] + [
+    f"{kind}-{k}" for kind in ("random", "ties", "zeros", "all_zero",
+                               "saturating", "tiny_scale")
+    for k in (5120, 13824)])
+def test_bit_arithmetic_matches_table_oracle(case):
+    """The bit-arithmetic code<->value conversions and the min-index top-1
+    pick equal a ``searchsorted``/table oracle, bit for bit."""
+    from repro.core.dtypes import (
+        fp4_code_to_value, fp4_value_to_code, fp6_code_to_value,
+        fp6_value_to_code,
+    )
+    if case == "conversions":
+        for grid, to_value, to_code, above in (
+                (_FP4_GRID, fp4_code_to_value, fp4_value_to_code, 7.0),
+                (_FP6_GRID, fp6_code_to_value, fp6_value_to_code, 7.75)):
+            codes = np.arange(len(grid))
+            np.testing.assert_array_equal(to_value(jnp.asarray(codes)), grid)
+            # on the grid, and above it (where a search puts len(grid))
+            v = np.concatenate([grid, [above, 8.0, 1e30, np.inf, np.nan]])
+            np.testing.assert_array_equal(
+                to_code(jnp.asarray(v, jnp.float32)),
+                np.searchsorted(grid, v))
+        np.testing.assert_array_equal(FP4_MAG_VALUES, _FP4_GRID)
+        np.testing.assert_array_equal(FP6_MAG_VALUES, _FP6_GRID)
+        return
+    kind, k = case.rsplit("-", 1)
+    x = _act_input(kind, int(k))
+    got = np.asarray(quantize_act_m2xfp(jnp.asarray(x)))
+    want = _oracle_quantize_act(x)
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+
+
+@pytest.mark.parametrize("k", [5120, 13824])
+def test_act_quantizer_lowers_without_loops_or_gathers(k):
+    """The online quantizer stays elementwise: no ``searchsorted`` loop, no
+    table gather, no ``cumsum`` (``reduce_window``) in its StableHLO."""
+    import re
+    text = quantize_act_m2xfp.lower(
+        jax.ShapeDtypeStruct((8, k), jnp.float32)).as_text()
+    for op in ("while", "gather", "reduce_window"):
+        assert not re.search(rf"stablehlo\.{op}\b", text), op
+
+
 @pytest.mark.smoke
 def test_pack_roundtrip_matches_fake_quant(rng):
     x = jnp.asarray(heavy_tailed(rng, (64, 256)))

@@ -92,18 +92,19 @@ def test_kernel_decode_equals_core_fake_quant():
 
 
 def test_bitmath_matches_luts():
-    """Kernel bit-arithmetic converters == core LUT converters on all codes."""
+    """The kernels' converters are core's, and match the grids on all codes."""
     from repro.kernels import bitmath
-    from repro.core.dtypes import (
-        fp4_code_to_value, fp6_code_to_value, FP4_MAG_VALUES, FP6_MAG_VALUES)
+    from repro.core import dtypes
+    from repro.core.dtypes import FP4_MAG_VALUES, FP6_MAG_VALUES
+    for name in ("exp2int", "fp4_code_to_value", "fp4_value_to_code",
+                 "fp6_code_to_value", "fp6_value_to_code"):
+        assert getattr(bitmath, name) is getattr(dtypes, name)
     c4 = jnp.arange(8)
-    assert jnp.array_equal(bitmath.fp4_mag_from_code(c4),
-                           fp4_code_to_value(c4))
-    assert jnp.array_equal(bitmath.fp4_code_from_mag(FP4_MAG_VALUES), c4)
+    assert jnp.array_equal(bitmath.fp4_code_to_value(c4), FP4_MAG_VALUES)
+    assert jnp.array_equal(bitmath.fp4_value_to_code(FP4_MAG_VALUES), c4)
     c6 = jnp.arange(32)
-    assert jnp.array_equal(bitmath.fp6_mag_from_code(c6),
-                           fp6_code_to_value(c6))
-    assert jnp.array_equal(bitmath.fp6_code_from_mag(FP6_MAG_VALUES), c6)
+    assert jnp.array_equal(bitmath.fp6_code_to_value(c6), FP6_MAG_VALUES)
+    assert jnp.array_equal(bitmath.fp6_value_to_code(FP6_MAG_VALUES), c6)
     # rtne parity on a dense sweep
     xs = jnp.linspace(-8, 8, 4097)
     from repro.core.dtypes import round_to_grid, FP4_E2M1, FP6_E2M3
