@@ -8,7 +8,8 @@ For every cell (all of BENCHMARK.json by default) this lowers and compiles
 ``ServeEngine`` against a described ``v5e:2x2`` topology, from shapes only,
 and prints ``memory_analysis()``: what the chip's compiler would refuse,
 and the argument and temporary bytes of each launch. With ``--reference``
-it also compiles the correctness reference at the cell's sample shapes.
+it also compiles the correctness reference (the layers of the cell's
+family, ``bench/references/<family>.py``) at the cell's sample shapes.
 Run it by hand before spending chip time; a 48-layer step takes about a
 minute to compile.
 """
@@ -81,8 +82,8 @@ def check_cell(name: str, reference: bool, one_chip) -> None:
             eng._prefill.lower(p, pre, c, i32, i32).compile())
     if reference:
         from harness import reference as ref_mod
+        family = spec.reference_module(cell.config["family"])
         r = cell.config["reference"]
-        m = ref_mod.ModelShape.of(cell.config)
         rows = -(-r["max_tokens"] // 256) * 256
         args = (jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip),
                 jax.ShapeDtypeStruct((r["max_seqs"], dep["max_len"]),
@@ -92,7 +93,8 @@ def check_cell(name: str, reference: bool, one_chip) -> None:
         for low, high in (("bfloat16", "float32"),
                           ("float8_e4m3fn", "bfloat16")):
             _report(f"reference ({low}, {high})", ref_mod._gaps.lower(
-                *args, m=m, low_name=low, high_name=high,
+                *args, family=family, m=family.Shape.of(cell.config),
+                low_name=low, high_name=high,
                 row_block=256, seq_block=min(2, r["max_seqs"])).compile())
 
 

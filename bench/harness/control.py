@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import check as _check
+from . import spec
 from .reference import reference_gaps
 
 __all__ = ["control_readings", "LOWER"]
@@ -33,8 +34,9 @@ def control_readings(key, model: dict, tokens, rows, served, n: int):
     sequence it belongs to and each one's gap in standard deviations."""
     _, control_top, _ = reference_gaps(key, model, tokens, rows, served,
                                        low=LOWER[0], high=LOWER[1])
+    vocab = spec.reference_module(model["family"]).Shape.of(model).vocab
     altered = np.array(served, np.int32)
-    altered[3::4] = (altered[3::4] + 1) % model["vocab_size"]
+    altered[3::4] = (altered[3::4] + 1) % vocab
     gap, _, sd = reference_gaps(key, model, tokens, rows,
                                 np.stack([served, control_top, altered]))
     names = ("program", "control", "altered_tokens")

@@ -1,4 +1,12 @@
-"""Plain reference of a served dense decoder (Qwen2/Qwen3 blocks).
+"""Plain reference of a served decoder: what every model family shares.
+
+The layer stack is the family's own, a module ``bench/references/<family>.py``
+found by the ``family`` of the configuration file
+(``spec.reference_module``; ``dense.py`` says what such a module exports).
+This file holds the rest: the embedding, one key per layer from the seed,
+the final norm, ``lm_head`` in blocks of rows and the gaps read there
+(``reference_gaps``), and the parts a block is built from (``_rms``,
+``_mm``, ``_rope``, ``_attention``).
 
 It imports nothing of the program and takes nothing the program made. The
 weights are drawn again from the seed by the recipe the benchmark defines
@@ -34,15 +42,16 @@ batching of requests into slots): logits at the requested rows only.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import spec
+
 __all__ = ["FP4_GRID", "FP6_GRID", "round_grid", "quantize_weight",
-           "quantize_act", "reference_gaps", "ModelShape"]
+           "quantize_act", "Precision", "reference_gaps"]
 
 FP4_GRID = np.array([0.0, 0.5, 1.0, 1.5, 2.0, 3.0, 4.0, 6.0], np.float32)
 FP6_GRID = np.array([m / 8.0 for m in range(8)] +
@@ -146,60 +155,8 @@ def quantize_act(x: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Model
+# Parts of a block, and the driver every family shares
 # ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class ModelShape:
-    """The published sizes a dense block needs, from the config file."""
-    d: int
-    n_layers: int
-    nh: int
-    nkv: int
-    hd: int
-    ff: int
-    vocab: int
-    eps: float
-    theta: float
-    qkv_bias: bool
-    qk_norm: bool
-
-    @classmethod
-    def of(cls, c: dict) -> "ModelShape":
-        return cls(d=c["hidden_size"], n_layers=c["num_hidden_layers"],
-                   nh=c["num_attention_heads"],
-                   nkv=c["num_key_value_heads"],
-                   hd=c.get("head_dim") or
-                   c["hidden_size"] // c["num_attention_heads"],
-                   ff=c["intermediate_size"], vocab=c["vocab_size"],
-                   eps=c["rms_norm_eps"], theta=c["rope_theta"],
-                   qkv_bias=bool(c.get("qkv_bias", False)),
-                   qk_norm=bool(c.get("qk_norm", False)))
-
-
-def _dense_init(key, d_in, d_out):
-    return (jax.random.truncated_normal(key, -3, 3, (d_in, d_out),
-                                        jnp.float32)
-            * d_in ** -0.5).astype(jnp.bfloat16)
-
-
-def _layer_weights(key, m: ModelShape) -> dict:
-    """One block's weights from its key, quantized, as bfloat16 (exact)."""
-    k_attn, k_mlp = jax.random.split(key)
-    ks = jax.random.split(k_attn, 4)
-    kf = jax.random.split(k_mlp, 3)
-    dense = {
-        "wq": _dense_init(ks[0], m.d, m.nh * m.hd),
-        "wk": _dense_init(ks[1], m.d, m.nkv * m.hd),
-        "wv": _dense_init(ks[2], m.d, m.nkv * m.hd),
-        "wo": _dense_init(ks[3], m.nh * m.hd, m.d),
-        "gate": _dense_init(kf[0], m.d, m.ff),
-        "up": _dense_init(kf[1], m.d, m.ff),
-        "down": _dense_init(kf[2], m.ff, m.d),
-    }
-    return {n: quantize_weight(w).astype(jnp.bfloat16)
-            for n, w in dense.items()}
-
 
 class Precision:
     """Where the served model rounds (``low``: activations, K/V, attention
@@ -269,30 +226,10 @@ def _attention(q, k, v, pr, q_block: int = 256):
     return jnp.moveaxis(out, 0, 1).reshape(n, L, nh * hd)
 
 
-def _block(h, w, pos, m: ModelShape, pr):
-    n, L, _ = h.shape
-    x = _rms(h, m.eps, pr)
-    q, k, v = (_mm(x, w[a], pr) for a in ("wq", "wk", "wv"))
-    if m.qkv_bias:                      # the seeded biases are zero
-        q, k, v = (t + jnp.zeros((), pr.low) for t in (q, k, v))
-    q = q.reshape(n, L, m.nh, m.hd)
-    k = k.reshape(n, L, m.nkv, m.hd)
-    v = v.reshape(n, L, m.nkv, m.hd)
-    if m.qk_norm:
-        q, k = _rms(q, m.eps, pr), _rms(k, m.eps, pr)
-    q, k = _rope(q, pos, m.theta, pr), _rope(k, pos, m.theta, pr)
-    h = (h + _mm(_attention(q, k, v, pr), w["wo"], pr)).astype(pr.low)
-    x = _rms(h, m.eps, pr)
-    gate, up = _mm(x, w["gate"], pr), _mm(x, w["up"], pr)
-    g = gate.astype(pr.high)
-    act = (jax.nn.sigmoid(g) * g).astype(pr.low) * up
-    return (h + _mm(act.astype(pr.low), w["down"], pr)).astype(pr.low)
-
-
 @functools.partial(jax.jit, static_argnames=(
-    "m", "low_name", "high_name", "row_block", "seq_block"))
-def _gaps(key, tokens, rows, check, *, m, low_name, high_name, row_block,
-          seq_block):
+    "family", "m", "low_name", "high_name", "row_block", "seq_block"))
+def _gaps(key, tokens, rows, check, *, family, m, low_name, high_name,
+          row_block, seq_block):
     pr = Precision(low_name, high_name)
     keys = jax.random.split(key, 8)
     embed = (jax.random.normal(keys[0], (m.vocab, m.d), jnp.float32)
@@ -302,12 +239,7 @@ def _gaps(key, tokens, rows, check, *, m, low_name, high_name, row_block,
     h = embed[tokens].astype(pr.low).reshape(n // seq_block, seq_block, L,
                                              m.d)
     del embed
-
-    def layer(h, lkey):
-        w = _layer_weights(lkey, m)        # once per layer, all sequences
-        return jax.lax.map(lambda hb: _block(hb, w, pos, m, pr), h), None
-
-    h, _ = jax.lax.scan(layer, h, jax.random.split(keys[2], m.n_layers))
+    h = family.layers(h, jax.random.split(keys[2], m.n_layers), pos, m, pr)
     head = (jax.random.normal(keys[1], (m.vocab, m.d), jnp.float32)
             * 0.02).astype(jnp.bfloat16).astype(pr.low).astype(
                 jnp.bfloat16).T
@@ -339,18 +271,21 @@ def reference_gaps(key, model: dict, tokens: np.ndarray, rows: np.ndarray,
     returns (gap, top, sd) where ``gap[..., i]`` is how far the logit of
     token ``check[..., i]`` lies below the best logit at that row (``check``
     may stack several candidate tokens per row), ``top[i]`` the best token
-    and ``sd[i]`` the standard deviation of the row's logits. The embedding is looked up in bfloat16 and rounded to
-    ``low`` with the residual stream. Each layer's weights are drawn once
-    and applied to ``seq_block`` sequences at a time, which bounds the
-    memory the activations take."""
-    m = ModelShape.of(model)
+    and ``sd[i]`` the standard deviation of the row's logits. The layers
+    are those of the model's ``family`` (``bench/references/<family>.py``).
+    The embedding is
+    looked up in bfloat16 and rounded to ``low`` with the residual stream.
+    Each layer's weights are drawn once and applied to ``seq_block``
+    sequences at a time, which bounds the memory the activations take."""
+    family = spec.reference_module(model["family"])
     check = np.asarray(check, np.int32)
     gap, top, sd = _gaps(key, jnp.asarray(tokens, jnp.int32),
-                     jnp.asarray(rows, jnp.int32),
-                     jnp.asarray(check.reshape(-1, check.shape[-1])),
-                     m=m,
-                     low_name=jnp.dtype(low).name,
-                     high_name=jnp.dtype(high).name, row_block=row_block,
-                     seq_block=min(seq_block, len(tokens)))
+                         jnp.asarray(rows, jnp.int32),
+                         jnp.asarray(check.reshape(-1, check.shape[-1])),
+                         family=family, m=family.Shape.of(model),
+                         low_name=jnp.dtype(low).name,
+                         high_name=jnp.dtype(high).name,
+                         row_block=row_block,
+                         seq_block=min(seq_block, len(tokens)))
     return (np.asarray(gap).reshape(check.shape), np.asarray(top),
             np.asarray(sd))

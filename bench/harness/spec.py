@@ -1,5 +1,6 @@
 """Find a cell's parts by name: BENCHMARK.json, its configuration file,
-its traffic mix, the per-layer metric readers and the family's work counts.
+its traffic mix, the per-layer metric readers, and the family's work
+counts and plain reference.
 
 Everything a cell needs is a file named after it, so a later change adds a
 configuration, a mix, a metric or a family by adding a file here and an
@@ -11,12 +12,14 @@ import dataclasses
 import importlib.util
 import json
 import os
+import sys
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
 
 __all__ = ["Cell", "load_cell", "load_benchmark", "metric_reader",
-           "counts_module", "load_peaks", "BENCH_DIR", "ROOT"]
+           "counts_module", "reference_module", "load_peaks", "BENCH_DIR",
+           "ROOT"]
 
 
 @dataclasses.dataclass
@@ -64,13 +67,23 @@ def load_cell(name: str, bench: dict | None = None) -> Cell:
 
 
 def _load_module(kind: str, name: str):
+    """The module ``<BENCH_DIR>/<kind>/<name>.py``, imported once per
+    path (and kept in ``sys.modules``, as a dataclass in it needs)."""
     path = os.path.join(BENCH_DIR, kind, f"{name}.py")
     if not os.path.exists(path):
         raise FileNotFoundError(f"no {kind} module {path}")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{kind}_{name.replace('.', '_')}", path)
+    mod_name = f"bench_{kind}_{name.replace('.', '_')}"
+    mod = sys.modules.get(mod_name)
+    if mod is not None and mod.__file__ == path:
+        return mod
+    spec = importlib.util.spec_from_file_location(mod_name, path)
     mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
+    sys.modules[mod_name] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[mod_name]
+        raise
     return mod
 
 
@@ -82,6 +95,13 @@ def metric_reader(name: str):
 def counts_module(family: str):
     """Work counts of one model family, ``bench/counts/<family>.py``."""
     return _load_module("counts", family)
+
+
+def reference_module(family: str):
+    """Plain reference of one model family, ``bench/references/<family>.py``:
+    its ``REGISTRY_KEYS``, ``Shape`` and ``layers`` (see ``dense.py``). A
+    family with no such file is an error, never another family's layers."""
+    return _load_module("references", family)
 
 
 def load_peaks(kind: str) -> dict:

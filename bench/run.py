@@ -22,7 +22,8 @@ files under ``bench/`` found by name. A run:
    compilations inside the window (there should be none); with
    ``--trace 1`` it also traces a steady stretch of the window;
 5. frees the program's state and compares a sample of the served tokens
-   with the plain reference (``bench.harness.reference``);
+   with the plain reference (``bench.harness.reference`` and the family's
+   layers, ``bench/references/<family>.py``);
 6. prints one JSON line: ``correct``, ``attempted``, ``failed``,
    ``metrics`` (end-to-end ones, or per-layer ones with ``--trace 1``),
    ``device``, ``breakdown`` (traced runs) and, last, ``checks``: each
@@ -51,15 +52,6 @@ from harness.loop import (itl_quantile_ms, prime, run_window,  # noqa: E402
                           tok_s, ttft_quantile_ms)
 from harness.traffic import ClosedLoopTraffic  # noqa: E402
 
-# the registry's ModelConfig field each published key of a config file is
-MODEL_KEYS = {
-    "hidden_size": "d_model", "intermediate_size": "d_ff",
-    "num_hidden_layers": "n_layers", "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads", "head_dim": "hd",
-    "vocab_size": "vocab_size", "rms_norm_eps": "norm_eps",
-    "rope_theta": "rope_theta", "tie_word_embeddings": "tie_embeddings",
-    "qkv_bias": "qkv_bias", "qk_norm": "qk_norm",
-}
 LOWERING = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
                 "/jax/compilation_cache/cache_misses": "misses"}
@@ -70,13 +62,15 @@ def log(msg: str) -> None:
 
 
 def model_config(conf: dict):
-    """The registry config the file names, checked key by key against the
-    file, so the file is the configuration that runs."""
+    """The registry config the file names, checked against the file by
+    the ``REGISTRY_KEYS`` of its family's reference, so the file is the
+    configuration that runs."""
     from repro.configs.registry import get_config
     dep = conf["deployment"]
     cfg = get_config(conf["model"], quant="serve",
                      quant_format=dep["codec"], kv_quant=dep["kv_quant"])
-    for key, field in MODEL_KEYS.items():
+    keys = spec.reference_module(conf["family"]).REGISTRY_KEYS
+    for key, field in keys.items():
         if key in conf and getattr(cfg, field) != conf[key]:
             raise SystemExit(f"{conf['model']}: file says {key}="
                              f"{conf[key]!r}, registry runs {field}="

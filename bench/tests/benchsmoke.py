@@ -1,8 +1,10 @@
 """Cells of the benchmark cut to the registry's smoke sizes, for CPU tests.
 
 ``smoke_cell(name)`` loads a cell of ``BENCHMARK.json`` and replaces the
-model's sizes with its registry ``SMOKE`` config, the deployment with 4
-slots of 128 tokens and chunk 8, and the traffic lengths with short ones;
+model's sizes with its registry ``SMOKE`` config (each published key of
+the file that its family's ``REGISTRY_KEYS`` maps, set to the field it
+maps to), the deployment with 4 slots of 128 tokens and chunk 8, and the
+traffic lengths with short ones;
 ``smoke_model(cell)`` is the matching registry config.
 ``fixed_steps(monkeypatch, n)`` makes every window of ``run.run`` hold
 ``n`` steps whatever the CPU's speed: its clock advances by a fixed
@@ -38,12 +40,10 @@ def smoke_model(cell, **overrides):
 def smoke_cell(name: str = "qwen2.5-14b.decode", **overrides):
     cell = spec.load_cell(name)
     cfg = smoke_model(cell, **overrides)
-    cell.config.update(
-        hidden_size=cfg.d_model, intermediate_size=cfg.d_ff,
-        num_hidden_layers=cfg.n_layers, num_attention_heads=cfg.n_heads,
-        num_key_value_heads=cfg.n_kv_heads, head_dim=cfg.hd,
-        vocab_size=cfg.vocab_size, rms_norm_eps=cfg.norm_eps,
-        rope_theta=cfg.rope_theta)
+    keys = spec.reference_module(cell.config["family"]).REGISTRY_KEYS
+    cell.config.update({key: getattr(cfg, field)
+                        for key, field in keys.items()
+                        if key in cell.config})
     cell.config["deployment"].update(n_slots=4, max_len=128,
                                      prefill_chunk=8)
     cell.config["reference"] = {"max_seqs": 4, "max_tokens": 256}

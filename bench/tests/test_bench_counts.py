@@ -9,8 +9,6 @@ import pytest
 import benchsmoke  # noqa: F401  (puts bench/ and src/ on the path)
 from harness import spec
 
-import run as bench_run
-
 
 def _gemm_stream_bytes(tree) -> int:
     from repro.core.codecs import PackedTensor
@@ -27,7 +25,8 @@ def _published(model: str) -> dict:
     from repro.configs.registry import get_config
     cfg = get_config(model, quant="serve")
     conf = {key: getattr(cfg, field)
-            for key, field in bench_run.MODEL_KEYS.items()}
+            for key, field in spec.reference_module("dense")
+            .REGISTRY_KEYS.items()}
     return dict(conf, model=model, family="dense",
                 deployment={"bits_per_weight": 4.5})
 
